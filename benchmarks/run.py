@@ -4,11 +4,13 @@
 
 Runs every reproduction benchmark at a CI-friendly budget (default 800
 samples; the paper protocol is 10K via --full) and prints a
-``name,seconds,headline`` CSV summary at the end.
+``name,seconds,headline`` CSV summary at the end.  Exits non-zero when any
+benchmark failed.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 import traceback
 
@@ -23,7 +25,7 @@ from benchmarks import (fig07_job_analysis, fig08_homogeneous,
 from benchmarks.common import FAST_METHODS, summarize_vs
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=800)
     ap.add_argument("--group-size", type=int, default=60)
@@ -34,6 +36,7 @@ def main() -> None:
     methods = FAST_METHODS
 
     rows = []
+    failed = []
 
     def bench(name, fn, headline_fn=lambda r: ""):
         t0 = time.perf_counter()
@@ -42,6 +45,7 @@ def main() -> None:
             rows.append((name, time.perf_counter() - t0, headline_fn(r)))
         except Exception as e:                           # noqa: BLE001
             traceback.print_exc()
+            failed.append(name)
             rows.append((name, time.perf_counter() - t0,
                          f"FAILED {type(e).__name__}"))
 
@@ -84,7 +88,13 @@ def main() -> None:
     print("\n==== benchmark summary (name,seconds,headline) ====")
     for name, dt, head in rows:
         print(f"{name},{dt:.1f},{head}")
+    if failed:
+        print(f"FAILED: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    sys.exit(main())

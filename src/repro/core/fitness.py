@@ -234,11 +234,15 @@ def population_energies(energy: jnp.ndarray, accel: jnp.ndarray) -> jnp.ndarray:
 
 
 def _population_makespans(params: FitnessParams, accel, prio, *,
-                          num_accels: int, use_kernel: bool) -> jnp.ndarray:
+                          num_accels: int, use_kernel) -> jnp.ndarray:
+    """``use_kernel``: False runs the jnp event scan, True the Pallas
+    kernel compiled with Mosaic (TPU only), ``kernels.ops.INTERPRET`` the
+    same kernel in the Pallas interpreter."""
     if use_kernel:
         from repro.kernels import ops as kops
-        return kops.population_makespan(accel, prio, params.lat, params.bw,
-                                        params.bw_sys, num_accels)
+        return kops.population_makespan(
+            accel, prio, params.lat, params.bw, params.bw_sys, num_accels,
+            interpret=use_kernel == kops.INTERPRET)
     return simulate_population(accel, prio, params.lat, params.bw,
                                params.bw_sys, num_accels)
 
@@ -381,6 +385,34 @@ def normalize_scenarios(scenarios, num_accels: Optional[int] = None,
     return ProblemSpec(params, num_accels, use_kernel, objective)
 
 
+def host_params(table: JobAnalysisTable, bw_sys: float,
+                objective: ObjectiveLike = "throughput") -> FitnessParams:
+    """A scenario's :class:`FitnessParams` as host numpy leaves: the exact
+    values :class:`FitnessFn` places on the device, built without touching
+    a device (a fleet parent ships these to the workers that own the
+    chips)."""
+    spec = as_objective_spec(objective)
+    lat = np.asarray(table.lat, dtype=np.float32)
+    energy = getattr(table, "energy", None)
+    if spec.needs_energy and energy is None:
+        raise ValueError(
+            f"objective {spec.token!r} needs an energy column, "
+            "but the job analysis table has none")
+    # scalar specs keep the () i32 code (bit-identical pytree to the
+    # pre-spec FitnessParams); multi-column specs carry an (M,) vector
+    codes = spec.codes
+    return FitnessParams(
+        lat=lat,
+        bw=np.asarray(table.bw, dtype=np.float32),
+        bw_sys=np.float32(bw_sys),
+        flops=np.float32(float(table.total_flops)),
+        energy=(np.asarray(energy, dtype=np.float32) if energy is not None
+                else np.zeros_like(lat)),
+        objective_code=(np.int32(codes[0]) if spec.is_scalar
+                        else np.asarray(codes, dtype=np.int32)),
+    )
+
+
 @dataclasses.dataclass
 class FitnessFn:
     table: JobAnalysisTable
@@ -388,7 +420,9 @@ class FitnessFn:
     # a registered name ('throughput' | 'latency' | 'energy' | 'edp' | any
     # register_objective'd name), a sequence of names, or an ObjectiveSpec
     objective: ObjectiveLike = "throughput"
-    use_kernel: bool = False         # route through the Pallas makespan kernel
+    # route through the Pallas makespan kernel: True compiles it with
+    # Mosaic (TPU only), kernels.ops.INTERPRET runs it in the interpreter
+    use_kernel: Union[bool, str] = False
 
     def __post_init__(self):
         self.bw_sys = float(self.bw_sys)
@@ -397,38 +431,12 @@ class FitnessFn:
             raise ValueError("FitnessFn needs a concrete objective "
                              "(name, name sequence, or ObjectiveSpec)")
         self.objective_spec = spec
-        self._lat = jnp.asarray(self.table.lat, dtype=jnp.float32)
-        self._bw = jnp.asarray(self.table.bw, dtype=jnp.float32)
-        self._flops = float(self.table.total_flops)
         self._A = int(self.table.num_accels)
-        self._energy = (jnp.asarray(self.table.energy, jnp.float32)
+        self.params = jax.tree.map(
+            jnp.asarray, host_params(self.table, self.bw_sys, spec))
+        self._energy = (self.params.energy
                         if getattr(self.table, "energy", None) is not None
                         else None)
-        if spec.needs_energy and self._energy is None:
-            raise ValueError(
-                f"objective {spec.token!r} needs an energy column, "
-                "but the job analysis table has none")
-        # scalar specs keep the () i32 code (bit-identical pytree to the
-        # pre-spec FitnessParams); multi-column specs carry an (M,) vector
-        codes = spec.codes
-        self.params = FitnessParams(
-            lat=self._lat,
-            bw=self._bw,
-            bw_sys=jnp.float32(self.bw_sys),
-            flops=jnp.float32(self._flops),
-            energy=(self._energy if self._energy is not None
-                    else jnp.zeros_like(self._lat)),
-            objective_code=(jnp.int32(codes[0]) if spec.is_scalar
-                            else jnp.asarray(codes, dtype=jnp.int32)),
-        )
-
-    def makespans(self, accel: jnp.ndarray, prio: jnp.ndarray) -> jnp.ndarray:
-        if self.use_kernel:
-            from repro.kernels import ops as kops
-            return kops.population_makespan(accel, prio, self._lat, self._bw,
-                                            self.bw_sys, self._A)
-        return simulate_population(accel, prio, self._lat, self._bw,
-                                   self.bw_sys, self._A)
 
     def energies(self, accel: jnp.ndarray) -> jnp.ndarray:
         """(P,) total group energy (J) of each assignment — order-free

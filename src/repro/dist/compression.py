@@ -18,7 +18,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 _EPS = 1e-12
@@ -91,11 +90,11 @@ def make_compressed_grad_fn(loss_fn, mesh, axis_name: str):
         if compiled is None:
             rep = lambda tree: jax.tree.map(lambda _: P(), tree)
             shd = lambda tree: jax.tree.map(lambda _: P(axis_name), tree)
-            compiled = jax.jit(shard_map(
+            compiled = jax.jit(jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(rep(params), shd(batch), shd(errors)),
                 out_specs=(P(), rep(params), shd(errors)),
-                check_rep=False))
+                check_vma=False))
             cache[key] = compiled
         return compiled(params, batch, errors)
 

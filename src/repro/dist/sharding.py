@@ -25,7 +25,7 @@ import contextlib
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _BATCH = object()    # sentinel: resolve to batch_axes(mesh)
 
@@ -54,9 +54,14 @@ DEFAULT_RULES: Dict[str, object] = {
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               devices=None) -> Mesh:
-    """Version-portable ``jax.make_mesh`` (newer jax adds ``axis_types``;
-    the default Auto semantics match older jax's only behaviour)."""
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` builds Explicit axes by default, and
+    ``with_sharding_constraint`` (``constrain``) accepts only Auto axes:
+    the model code here annotates shardings and lets the compiler
+    propagate them."""
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
                          devices=devices)
 
 

@@ -4,11 +4,16 @@
 CPU-testable end to end: each worker subprocess gets
 ``--xla_force_host_platform_device_count=<devices_per_worker>`` in its
 ``XLA_FLAGS``, so a laptop CI job brings up a genuine 2-worker x
-4-device fleet.  On real multi-host accelerators the same launcher runs
-with ``devices_per_worker=None`` (each worker sees its host's devices)
-and ``distributed=True`` (one global JAX runtime via
-``jax.distributed.initialize``; scheduling stays process-local because
-the whole stack dispatches over ``jax.local_devices()``).
+4-device fleet.  On a TPU host, ``devices_per_worker=None`` gives every
+worker ONE chip of its own: a chip belongs to one process, so worker
+``i`` sees only chip ``i`` through libtpu's per-process visibility
+variables (:func:`chip_env`).  ``distributed=True`` runs one global JAX
+runtime via ``jax.distributed.initialize`` instead; scheduling stays
+process-local because the whole stack dispatches over
+``jax.local_devices()``.
+
+Each worker's stderr goes to ``<log_dir>/<worker id>.log``; a worker
+that fails or dies takes the tail of its log into the parent's error.
 
     cfg = FleetConfig(num_workers=2, devices_per_worker=4, budget=300)
     with launch_fleet(cfg) as fleet:
@@ -21,12 +26,14 @@ imports + device init), so ``run`` measures scheduling, not startup.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import os
 import queue
 import socket
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -40,7 +47,8 @@ class FleetConfig:
 
     num_workers         scheduler processes
     devices_per_worker  fake host-platform devices per worker (None:
-                        inherit the environment — real accelerators)
+                        real accelerators — on a TPU host each worker is
+                        pinned to a chip of its own)
     budget / strategy   the per-worker StreamingScheduler defaults
     stream              StreamConfig field overrides for every worker
                         (dict, e.g. {"batch_rows": 4})
@@ -70,6 +78,8 @@ class FleetConfig:
                         worker; ``mark_warm()`` sets the boundary and
                         ``worker_stats()`` reports
                         compiles / recompiles_post_warmup
+    log_dir             directory of the per-worker stderr logs (None: a
+                        new temporary directory)
     """
     num_workers: int = 2
     devices_per_worker: Optional[int] = None
@@ -85,6 +95,7 @@ class FleetConfig:
     ready_timeout_s: float = 120.0
     obs: Optional[Dict] = None
     recompile_guard: bool = False
+    log_dir: Optional[str] = None
 
     def __post_init__(self):
         if self.num_workers < 1:
@@ -105,15 +116,39 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
+def tpu_chip_count() -> int:
+    """TPU chips on this host, counted from their device files, so the
+    launcher learns it without loading libtpu (which would take every
+    chip from the workers).  0 when ``JAX_PLATFORMS`` rules the TPU out."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def chip_env(chip: int, port: int) -> Dict[str, str]:
+    """libtpu's per-process visibility: the process sees chip ``chip``
+    alone, as a 1x1x1 slice of its own with its own port.  Each pinned
+    process loads libtpu for a different chip, which its host-wide load
+    lock would otherwise refuse."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+
+
 class WorkerHandle:
     """One worker subprocess: stdin for commands, a reader thread
     draining stdout protocol lines into the fleet's shared inbox."""
 
     def __init__(self, worker_id: str, proc: subprocess.Popen,
-                 inbox: "queue.Queue[Tuple[str, Dict]]"):
+                 inbox: "queue.Queue[Tuple[str, Dict]]", log_path: str):
         self.worker_id = worker_id
         self.proc = proc
+        self.log_path = log_path
         self._inbox = inbox
+        self.info: Dict = {}          # the worker's "ready" message
         self.outstanding = 0          # chunks sent, not yet done
         self.stats: Dict = {}         # final worker-side rollup (on stop)
         self.stats_snapshot: Optional[Dict] = None   # router delta base
@@ -129,6 +164,17 @@ class WorkerHandle:
                 except json.JSONDecodeError:
                     pass              # torn line at kill time
         self._inbox.put((self.worker_id, {"ok": "eof"}))
+
+    def failure(self, msg, lines: int = 40) -> str:
+        """An error message for this worker: what it reported (or the
+        parent saw) plus the tail of its stderr log."""
+        try:
+            with open(self.log_path, errors="replace") as f:
+                tail = "".join(f.readlines()[-lines:])
+        except OSError as e:
+            tail = f"<no log: {e}>"
+        return (f"fleet worker {self.worker_id} failed: {msg}\n"
+                f"--- {self.log_path} (last {lines} lines) ---\n{tail}")
 
     def send(self, msg: Dict) -> None:
         self.proc.stdin.write(json.dumps(msg) + "\n")
@@ -161,6 +207,14 @@ class Fleet:
         self.inbox: "queue.Queue[Tuple[str, Dict]]" = queue.Queue()
         self.workers: List[WorkerHandle] = []
         self.last_metrics = None
+        self.log_dir = cfg.log_dir or tempfile.mkdtemp(prefix="fleet-logs-")
+        os.makedirs(self.log_dir, exist_ok=True)
+        self.chips = (tpu_chip_count() if cfg.devices_per_worker is None
+                      and not cfg.distributed else 0)
+        if self.chips and cfg.num_workers > self.chips:
+            raise ValueError(
+                f"{cfg.num_workers} workers need a TPU chip each; this "
+                f"host has {self.chips}")
         coordinator = (f"127.0.0.1:{_free_port()}"
                        if cfg.distributed else None)
         for i in range(cfg.num_workers):
@@ -189,11 +243,16 @@ class Fleet:
             env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_"
                                 f"device_count={self.cfg.devices_per_worker}"
                                 ).strip()
-        proc = subprocess.Popen(
-            [sys.executable, "-u", "-m", "repro.fleet.worker"],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, env=env, text=True)
-        return WorkerHandle(f"w{i}", proc, self.inbox)
+        elif self.chips:
+            env.update(chip_env(i, _free_port()))
+        wid = f"w{i}"
+        log_path = os.path.join(self.log_dir, f"{wid}.log")
+        with open(log_path, "w") as log:
+            proc = subprocess.Popen(
+                [sys.executable, "-u", "-m", "repro.fleet.worker"],
+                stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+                stderr=log, env=env, text=True)
+        return WorkerHandle(wid, proc, self.inbox, log_path)
 
     def _init_msg(self, i: int, coordinator: Optional[str]) -> Dict:
         cfg = self.cfg
@@ -220,14 +279,20 @@ class Fleet:
                     timeout=max(0.0, deadline - time.monotonic()))
             except queue.Empty:
                 self.close()
-                raise TimeoutError(
-                    f"fleet startup: workers {sorted(pending)} not ready "
-                    f"within {self.cfg.ready_timeout_s:.0f}s")
+                raise TimeoutError("\n".join(
+                    self._handle(wid).failure(
+                        f"not ready within {self.cfg.ready_timeout_s:.0f}s")
+                    for wid in sorted(pending)))
             if msg.get("ok") == "ready":
+                self._handle(wid).info = msg
                 pending.discard(wid)
             elif msg.get("ok") in ("error", "eof"):
                 self.close()
-                raise RuntimeError(f"worker {wid} failed at init: {msg}")
+                raise RuntimeError(self._handle(wid).failure(
+                    f"at init: {msg}"))
+
+    def _handle(self, wid: str) -> WorkerHandle:
+        return next(w for w in self.workers if w.worker_id == wid)
 
     # -- serving --------------------------------------------------------------
     def run(self, requests: Sequence = (), prepared: Sequence = (),
@@ -266,7 +331,7 @@ class Fleet:
             if msg.get("ok") == "warmed":
                 pending.discard(wid)
             elif msg.get("ok") in ("error", "eof"):
-                raise RuntimeError(f"worker {wid} failed: {msg}")
+                raise RuntimeError(self._handle(wid).failure(msg))
 
     def mark_warm(self) -> None:
         """Tell every worker its RecompileGuard warmup is over: compiles
@@ -281,7 +346,7 @@ class Fleet:
             if msg.get("ok") == "warm":
                 pending.discard(wid)
             elif msg.get("ok") in ("error", "eof"):
-                raise RuntimeError(f"worker {wid} failed: {msg}")
+                raise RuntimeError(self._handle(wid).failure(msg))
 
     def worker_stats(self) -> Dict[str, Dict]:
         """Raw lifetime worker rollups (a 'stats' round trip to every
@@ -298,7 +363,7 @@ class Fleet:
                 stats[wid] = msg.get("stats", {})
                 pending.discard(wid)
             elif msg.get("ok") in ("error", "eof"):
-                raise RuntimeError(f"worker {wid} failed: {msg}")
+                raise RuntimeError(self._handle(wid).failure(msg))
         return stats
 
     def close(self) -> None:

@@ -278,7 +278,7 @@ class FleetRouter:
                 members = self._chunk_members.pop((wid, msg["chunk"]))
                 results.extend(self._decode(wid, members, msg))
             elif msg.get("ok") in ("error", "eof"):
-                raise RuntimeError(f"fleet worker {wid} failed: {msg}")
+                raise RuntimeError(self._by_id(wid).handle.failure(msg))
         wall = self._clock()
         for w in self.wq:
             w.queues.check()
@@ -359,7 +359,7 @@ class FleetRouter:
                                          msg.get("stats", {}))
                 pending.discard(wid)
             elif msg.get("ok") in ("error", "eof"):
-                raise RuntimeError(f"fleet worker {wid} failed: {msg}")
+                raise RuntimeError(self._by_id(wid).handle.failure(msg))
         for w in self.wq:
             stats.setdefault(w.worker_id, {})
             stats[w.worker_id]["router_sent"] = w.sent

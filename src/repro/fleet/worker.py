@@ -79,7 +79,7 @@ def encode_prepared(p) -> Dict:
         "params": {k: encode_array(v)
                    for k, v in fit.params._asdict().items()},
         "num_accels": int(fit.num_accels),
-        "use_kernel": bool(fit.use_kernel),
+        "use_kernel": fit.use_kernel,
         "objective": None if spec is None else list(spec.names),
         "seed": int(p.seed), "uid": int(p.uid),
         "budget": p.budget, "strategy": strategy,
@@ -87,28 +87,40 @@ def encode_prepared(p) -> Dict:
     }
 
 
-class _WireFit:
-    """The fit-like adapter a decoded prepared scenario schedules as:
-    exactly the attribute surface admission/dispatch/memo touch
-    (``FitnessFn`` duck type — tables + executable statics)."""
+class HostFit:
+    """A scenario held as host numpy tables: exactly the attribute surface
+    admission/dispatch/memo touch (``FitnessFn`` duck type — tables +
+    executable statics).  Workers schedule decoded prepared scenarios as
+    these; a fleet parent builds them with :meth:`from_table` so that it
+    never places an array on a chip its workers own."""
 
-    def __init__(self, params, num_accels: int, use_kernel: bool,
+    def __init__(self, params, num_accels: int, use_kernel,
                  objective_names: Optional[List[str]]):
         from repro.core.fitness import FitnessParams, ObjectiveSpec
         self.params = FitnessParams(**params)
         self.num_accels = int(num_accels)
-        self.use_kernel = bool(use_kernel)
+        self.use_kernel = use_kernel
         self.objective_spec = (None if objective_names is None
                                else ObjectiveSpec(tuple(objective_names)))
         self.objective = self.objective_spec
         self.group_size = int(np.asarray(self.params.lat).shape[-2])
         self.bw_sys = float(np.asarray(self.params.bw_sys))
 
+    @classmethod
+    def from_table(cls, table, bw_sys: float) -> "HostFit":
+        """The throughput-objective scenario ``FitnessFn(table, bw_sys)``
+        would place on the device."""
+        from repro.core.fitness import as_objective_spec, host_params
+        spec = as_objective_spec("throughput")
+        params = host_params(table, bw_sys, spec)
+        return cls(params._asdict(), table.num_accels, False,
+                   list(spec.names))
+
 
 def decode_prepared(d: Dict):
     from repro.stream.service import PreparedScenario
-    fit = _WireFit({k: decode_array(v) for k, v in d["params"].items()},
-                   d["num_accels"], d["use_kernel"], d["objective"])
+    fit = HostFit({k: decode_array(v) for k, v in d["params"].items()},
+                  d["num_accels"], d["use_kernel"], d["objective"])
     return PreparedScenario(fit=fit, seed=d["seed"], uid=d["uid"],
                             budget=d["budget"], strategy=d["strategy"],
                             priority=d["priority"],
@@ -195,8 +207,14 @@ class _Worker:
         self.peak_depth = 0
         self.early_flushes = 0
         self.refinements = 0
+        dev = jax.local_devices()[0]
+        # a pinned worker's chip is a 1x1x1 slice of its own, so its
+        # device coords are (0, 0, 0) on every chip: report the chip the
+        # launcher pinned it to instead (None when unpinned)
         _emit({"ok": "ready", "worker": self.worker_id,
-               "devices": len(jax.local_devices())})
+               "devices": len(jax.local_devices()),
+               "platform": dev.platform, "device_kind": dev.device_kind,
+               "chip": os.environ.get("TPU_VISIBLE_CHIPS")})
 
     def handle_run(self, msg: Dict) -> None:
         requests = [decode_request(d) for d in msg.get("requests", ())]
@@ -250,6 +268,8 @@ class _Worker:
 
 
 def main() -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     worker: Optional[_Worker] = None
     for line in sys.stdin:
         line = line.strip()

@@ -91,7 +91,7 @@ def _round_up(x: int, m: int) -> int:
                               "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D) -> (B, Hq, S, D)."""
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]

@@ -82,7 +82,7 @@ def _round_up(x: int, m: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("pop_block", "interpret"))
 def makespan_pallas(qlat, qbw, count, bw_sys, *, pop_block: int = 8,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """qlat/qbw: (P, A, G) f32 per-queue-slot tables; count: (P, A) int32;
     returns (P,) makespans."""
     P, A, G = qlat.shape

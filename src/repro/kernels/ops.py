@@ -1,8 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends (this container) the kernels run in ``interpret=True``
-mode — the kernel bodies execute eagerly for correctness validation; on a
-real TPU ``interpret=False`` compiles them to Mosaic.
+Every wrapper compiles its kernel with Mosaic unless the caller passes
+``interpret=True``, which runs the kernel body in the Pallas interpreter.
+Nothing picks the mode from the backend: a compiled kernel requested off
+a TPU raises (Pallas: "Only interpret mode is supported on CPU backend")
+instead of quietly running interpreted.  Flags that route a whole model or
+search through a kernel (``use_kernel``, ``use_flash``) take the value
+:data:`INTERPRET` for the interpreted kernel.
 """
 from __future__ import annotations
 
@@ -16,8 +20,9 @@ from repro.kernels.makespan import makespan_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+#: ``use_kernel`` / ``use_flash`` value that runs the Pallas kernel in the
+#: interpreter (tests off the TPU); ``True`` compiles it with Mosaic.
+INTERPRET = "interpret"
 
 
 # ---------------------------------------------------------------------------
@@ -25,7 +30,7 @@ def _default_interpret() -> bool:
 # ---------------------------------------------------------------------------
 @partial(jax.jit, static_argnames=("num_accels", "interpret"))
 def population_makespan(accel, prio, lat, bw, bw_sys, num_accels: int,
-                        interpret: bool | None = None):
+                        interpret: bool = False):
     """Drop-in replacement for ``bw_allocator.simulate_population``.
 
     accel: (P, G) int32, prio: (P, G) f32, lat/bw: (G, A) f32 job tables.
@@ -33,7 +38,6 @@ def population_makespan(accel, prio, lat, bw, bw_sys, num_accels: int,
     Pallas kernel with the queue tables resident in VMEM."""
     from repro.core.encoding import decode
 
-    interpret = _default_interpret() if interpret is None else interpret
     lat = lat.astype(jnp.float32)
     bw = jnp.maximum(bw.astype(jnp.float32), 1e-3)
 
@@ -52,12 +56,11 @@ def population_makespan(accel, prio, lat, bw, bw_sys, num_accels: int,
 # ---------------------------------------------------------------------------
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B, S, Hq, D); k, v: (B, S, Hkv, D) -> (B, S, Hq, D).
 
     Layout matches ``repro.models.layers`` (seq-major heads); the kernel
     operates on (B, H, S, D)."""
-    interpret = _default_interpret() if interpret is None else interpret
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -72,9 +75,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # selective scan
 # ---------------------------------------------------------------------------
 def ssm_scan(x, dt, A, B, C, *, chunk: int = 128, d_block: int = 256,
-             interpret: bool | None = None):
+             interpret: bool = False):
     """Same contract as ``repro.models.mamba.selective_scan``."""
-    interpret = _default_interpret() if interpret is None else interpret
     ch = min(chunk, max(8, x.shape[1]))
     return ssm_scan_pallas(x, dt, A, B, C, chunk=ch, d_block=d_block,
                            interpret=interpret)

@@ -60,7 +60,7 @@ def _round_up(x: int, m: int) -> int:
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "d_block", "interpret"))
 def ssm_scan_pallas(x, dt, A, B, C, *, chunk: int = 128, d_block: int = 256,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """x, dt: (Bt, L, D); A: (D, N); B, C: (Bt, L, N).
     Returns (y (Bt, L, D) f32, h_final (Bt, D, N) f32)."""
     Bt, L, D = x.shape

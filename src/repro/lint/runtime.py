@@ -59,13 +59,10 @@ class RecompileError(RuntimeError):
     """A jit compilation happened inside a region that promised none."""
 
 
-# jax 0.4.x emits "Compiling <fn> with global shapes and types ..." from
-# jax._src.interpreters.pxla at DEBUG; older/newer spellings fall back
-# to jax._src.dispatch.  Both may exist; listening twice is harmless
-# because each compile logs "Compiling" once per module that owns it.
+# jax 0.9.0 emits "Compiling <fn> with global shapes and types ..." from
+# jax._src.interpreters.pxla at DEBUG, once per compile.
 _COMPILE_LOGGER_NAMES = (
     "jax._src.interpreters.pxla",
-    "jax._src.dispatch",
 )
 _COMPILE_RE = re.compile(r"^Compiling (\S+)")
 

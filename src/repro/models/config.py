@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -65,7 +65,9 @@ class ModelConfig:
     dtype: str = "bfloat16"
     scan_layers: bool = True     # scan over layers (False = unrolled, used by
                                  # the dry-run for exact cost_analysis)
-    use_flash: bool = False      # route attention through the Pallas kernel
+    use_flash: Union[bool, str] = False  # route attention / the SSM scan
+                                 # through the Pallas kernels (True: Mosaic,
+                                 # kernels.ops.INTERPRET: the interpreter)
     remat: bool = True
 
     # ---- derived -----------------------------------------------------------

@@ -184,12 +184,14 @@ def _chunked_attention(q, k, v, *, causal, window, q_chunk, dtype):
 
 def full_attention(p: AttnParams, li, x, *, n_heads, n_kv, head_dim,
                    rope_theta, window=0, positions=None, use_flash=False,
-                   flash_interpret=True, causal=True, q_chunk=0):
+                   causal=True, q_chunk=0):
     """Training/prefill self-attention over the full sequence.
 
     ``q_chunk`` > 0 and S > 2*q_chunk routes through exact chunked
     attention (memory O(S * q_chunk) instead of O(S^2)); ``use_flash``
-    routes through the Pallas kernel instead (TPU).  ``li`` is unused
+    routes through the Pallas kernel instead: compiled with Mosaic (TPU),
+    or in the Pallas interpreter when it is ``kernels.ops.INTERPRET``.
+    ``li`` is unused
     (params come pre-sliced by the layer scan)."""
     wq, wk, wv, wo = p
     B, S, _ = x.shape
@@ -206,7 +208,7 @@ def full_attention(p: AttnParams, li, x, *, n_heads, n_kv, head_dim,
     if use_flash:
         from repro.kernels import ops as kops
         ctx = kops.flash_attention(q, k, v, causal=causal, window=window,
-                                   interpret=flash_interpret)
+                                   interpret=use_flash == kops.INTERPRET)
     elif q_chunk and S > 2 * q_chunk and S % q_chunk == 0:
         ctx = _chunked_attention(q, k, v, causal=causal, window=window,
                                  q_chunk=q_chunk, dtype=x.dtype)

@@ -181,7 +181,9 @@ def mamba1_block(lp: Mamba1Params, x, cfg: ModelConfig, state=None):
         A = -jnp.exp(lp.A_log)
         if cfg.use_flash:
             from repro.kernels import ops as kops
-            y, h_fin = kops.ssm_scan(x_c, dt, A, B_ssm, C_ssm)
+            y, h_fin = kops.ssm_scan(
+                x_c, dt, A, B_ssm, C_ssm,
+                interpret=cfg.use_flash == kops.INTERPRET)
         elif cfg.ssm_time_chunk:
             y, h_fin = selective_scan_chunked(x_c, dt, A, B_ssm, C_ssm,
                                               chunk=cfg.ssm_time_chunk)
@@ -275,7 +277,9 @@ def mamba2_block(lp: Mamba2Params, x, cfg: ModelConfig, state=None):
         x_c = jax.nn.silu(x_c.astype(jnp.float32)).astype(x.dtype)
         if cfg.use_flash:
             from repro.kernels import ops as kops
-            y, h_fin = kops.ssm_scan(x_c, dt_full, A_full, B_ssm, C_ssm)
+            y, h_fin = kops.ssm_scan(
+                x_c, dt_full, A_full, B_ssm, C_ssm,
+                interpret=cfg.use_flash == kops.INTERPRET)
         elif cfg.ssm_time_chunk:
             y, h_fin = selective_scan_chunked(x_c, dt_full, A_full, B_ssm,
                                               C_ssm,

@@ -281,28 +281,30 @@ class MultiTenantEngine:
             raise ValueError("execute=True needs prompts "
                              "(prefill-job uid -> token array)")
         table = self.analyze(jobs)
-        fit = FitnessFn(table, bw_sys=self.system_bw)
         method = method or self.method
         strategy = get_strategy(method)
         stream_res = None
-        if strategy.device_resident:
+        if not strategy.device_resident:
+            res: SearchResult = run_strategy(
+                strategy, FitnessFn(table, bw_sys=self.system_bw),
+                budget=self.budget, seed=self.seed)
+        else:
             slo = self.slo_for(jobs)
             if self.fleet is not None:
+                # the fleet's workers own the chips: ship host tables and
+                # never place an array on a device from this process
+                from repro.fleet.worker import HostFit
                 from repro.stream.service import PreparedScenario
                 stream_res = self.fleet.run(prepared=[PreparedScenario(
-                    fit=fit, seed=self.seed, budget=self.budget,
-                    strategy=strategy, priority=slo.priority,
-                    deadline_s=slo.deadline_s)])[0]
+                    fit=HostFit.from_table(table, self.system_bw),
+                    seed=self.seed, budget=self.budget, strategy=strategy,
+                    priority=slo.priority, deadline_s=slo.deadline_s)])[0]
             else:
                 stream_res = self.stream_service().schedule_prepared(
-                    fit, seed=self.seed, budget=self.budget,
-                    strategy=strategy,
+                    FitnessFn(table, bw_sys=self.system_bw),
+                    seed=self.seed, budget=self.budget, strategy=strategy,
                     priority=slo.priority, deadline_s=slo.deadline_s)
             res = stream_res.to_search_result()
-        else:
-            res: SearchResult = run_strategy(strategy, fit,
-                                             budget=self.budget,
-                                             seed=self.seed)
         local = decode_to_lists(res.best_accel, res.best_prio,
                                 len(self.submeshes))
         makespan = simulate_numpy(local, table.lat, table.bw, self.system_bw)

@@ -484,3 +484,31 @@ def test_fleet_config_validation():
         FleetConfig(devices_per_worker=0)
     with pytest.raises(ValueError, match="chunk_rows"):
         FleetConfig(chunk_rows=0)
+
+
+def test_chip_env_pins_one_chip_per_worker(monkeypatch):
+    """On a TPU host every worker sees exactly its own chip; off one (or
+    under JAX_PLATFORMS=cpu) the launcher pins nothing."""
+    from repro.fleet.launch import chip_env, tpu_chip_count
+    envs = [chip_env(i, 9000 + i) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert tpu_chip_count() == 0
+
+
+def test_worker_failure_carries_its_log(tmp_path):
+    """A worker that fails at init: the parent's error names the worker
+    and carries the tail of its stderr log."""
+    cfg = FleetConfig(num_workers=1, devices_per_worker=1, budget=BUDGET,
+                      stream={"no_such_field": 1}, log_dir=str(tmp_path))
+    with pytest.raises(RuntimeError) as err:
+        launch_fleet(cfg)
+    msg = str(err.value)
+    assert "fleet worker w0 failed" in msg and "no_such_field" in msg
+    assert str(tmp_path / "w0.log") in msg
+    assert (tmp_path / "w0.log").exists()
+

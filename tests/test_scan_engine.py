@@ -60,7 +60,8 @@ def test_scan_engine_matches_loop_bitwise(objective, budget):
 
 def test_scan_engine_matches_loop_with_kernel():
     """The Pallas makespan path must trace inside the generation scan."""
-    fit = _fitness(use_kernel=True)
+    from repro.kernels.ops import INTERPRET
+    fit = _fitness(use_kernel=INTERPRET)
     cfg = MagmaConfig(population=10)
     r_loop = magma_search(fit, budget=50, cfg=cfg, seed=1, engine="loop")
     r_scan = magma_search(fit, budget=50, cfg=cfg, seed=1, engine="scan")

@@ -205,3 +205,43 @@ def test_schedule_front_serves_the_frontier(engine):
         assert sorted(u for q in pt["queues"] for u in q) == all_uids
         assert pt["makespan_s"] > 0 and np.isfinite(pt["makespan_s"])
         assert set(pt["objectives"]) == {"latency", "energy", "edp"}
+
+
+def test_fleet_path_ships_host_tables(engine):
+    """With a fleet attached the engine builds no device array: it ships
+    host numpy tables equal to the FitnessFn's, and the schedule equals
+    the in-process stream's."""
+    from repro.core.fitness import FitnessFn
+    from repro.stream import StreamConfig, StreamingScheduler
+
+    class InProcessFleet:
+        """Stands in for repro.fleet.Fleet: serves prepared scenarios the
+        way a worker does, after checking what crossed the wire."""
+        def __init__(self):
+            self.svc = StreamingScheduler(budget=engine.budget,
+                                          stream=StreamConfig())
+            self.seen = []
+
+        def run(self, prepared=()):
+            self.seen.extend(prepared)
+            return self.svc.run(prepared=prepared)
+
+    reqs = [("granite-3-2b", 64, 4)] * 3
+    jobs = engine.jobs_for_requests(reqs)
+    fleet = InProcessFleet()
+    with MultiTenantEngine(list(engine.tenants.values()),
+                           default_submeshes(), budget=engine.budget,
+                           group_size=engine.group_size,
+                           decode_window=engine.decode_window,
+                           seed=engine.seed, fleet=fleet) as fleet_engine:
+        out = fleet_engine.schedule(jobs)
+    fleet.svc.close()
+    (p,) = fleet.seen
+    ref_fit = FitnessFn(out["table"], bw_sys=engine.system_bw)
+    for leaf, ref in zip(p.fit.params, ref_fit.params):
+        assert type(leaf).__module__.startswith("numpy")
+        np.testing.assert_array_equal(leaf, np.asarray(ref))
+    ref = engine.schedule(jobs)
+    assert out["queues"] == ref["queues"]
+    assert out["makespan_s"] == ref["makespan_s"]
+

@@ -172,13 +172,14 @@ def test_device_baselines_improve_over_first_generation(name):
 # seed discipline: the state carries the key -> pinned results
 # ---------------------------------------------------------------------------
 PINNED_BEST = {
-    # computed once on CPU jax 0.4.37; threefry is deterministic across
-    # hosts/devices/jit boundaries, so these must reproduce everywhere
-    "magma": 5.88925313949585,
-    "random": 3.7513720989227295,
-    "stdga": 5.8267741203308105,
-    "de": 4.13724946975708,
-    "pso": 4.649626731872559,
+    # computed on CPU jax 0.9.0 (whose default threefry draws other bits
+    # than 0.4.x); threefry is deterministic across hosts/devices/jit
+    # boundaries, so these must reproduce everywhere
+    "magma": 5.290772914886475,
+    "random": 4.048213481903076,
+    "stdga": 6.237369060516357,
+    "de": 4.318979740142822,
+    "pso": 3.9535927772521973,
 }
 
 

@@ -44,7 +44,8 @@ def test_warmstart_jitter_pinned_seed():
     new jitter.  Values pinned like tests/test_strategies.py pins
     best-fitness per strategy (jax threefry is stable across
     hosts/devices), so any accidental host-RNG leak or key-order change
-    in the jitter path fails loudly."""
+    in the jitter path fails loudly.  Pinned on jax 0.9.0, whose default
+    ``jax_threefry_partitionable=True`` draws different bits than 0.4.x."""
     from repro.core.encoding import random_population
     ws = WarmStartEngine()
     ws.remember("Vision", random_population(jax.random.PRNGKey(0), 8, 10, 4))
@@ -55,9 +56,9 @@ def test_warmstart_jitter_pinned_seed():
     p3 = ws.init_population("Vision", jax.random.PRNGKey(4), 10, 4)
     assert (np.asarray(p1.prio) != np.asarray(p3.prio)).any()
     # accel transfers un-jittered; prio jitter is pinned to the key
-    assert int(np.asarray(p1.accel).sum()) == 104
+    assert int(np.asarray(p1.accel).sum()) == 113
     assert float(np.asarray(p1.prio, dtype=np.float64).sum()) == \
-        pytest.approx(44.240133725106716, rel=1e-9)
+        pytest.approx(35.40254068002105, rel=1e-9)
 
 
 def test_warmstart_remember_is_content_addressed():
