@@ -1,0 +1,10 @@
+"""The benchmark's own tests: CPU only, small sizes, no persistent
+compilation cache (nothing is written to the checkout's ``.jax_cache``)."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(REPO, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
