@@ -16,6 +16,9 @@ that share the system bandwidth:
 Exactly one job finishes per event step, so ``G`` steps simulate a group of
 ``G`` jobs; ties drain in consecutive zero-dt steps.  The scan is jit- and
 vmap-friendly: MAGMA evaluates a whole population with one vmapped call.
+Its parts run under the named scopes ``fitness.decode``,
+``fitness.tables`` (queue-table gathers) and ``fitness.events`` (the
+scan): metadata only, which a device trace reads back.
 
 ``simulate_numpy`` is the float64 oracle used by the tests and as the
 reference for the Pallas ``makespan`` kernel.
@@ -52,7 +55,8 @@ def simulate_tables(qlat: jnp.ndarray, qbw: jnp.ndarray, count: jnp.ndarray,
     per-event quantity is a dense (P, A) array (no per-individual scatter
     or gather chains, which XLA:CPU serializes)."""
     P, A, G = qlat.shape
-    qbytes = qlat * qbw                  # remaining work, paper's CurJobs
+    with jax.named_scope("fitness.tables"):
+        qbytes = qlat * qbw              # remaining work, paper's CurJobs
     iota_a = jax.lax.broadcasted_iota(jnp.int32, (P, A), 1)
 
     def pick(q, ptr):
@@ -81,7 +85,8 @@ def simulate_tables(qlat: jnp.ndarray, qbw: jnp.ndarray, count: jnp.ndarray,
         rem = jnp.where(fin_oh, jnp.where(nxt_active, nxt, 0.0), rem)
         return (t + dt, rem, ptr), None
 
-    (t, _, _), _ = jax.lax.scan(step, (t0, rem0, ptr0), None, length=G)
+    with jax.named_scope("fitness.events"):
+        (t, _, _), _ = jax.lax.scan(step, (t0, rem0, ptr0), None, length=G)
     return t
 
 
@@ -110,8 +115,10 @@ def simulate_population(accel: jnp.ndarray, prio: jnp.ndarray, lat: jnp.ndarray,
     bwf = bw.astype(jnp.float32)
 
     def tables_one(a, p):
-        sched = decode(a, p, num_accels)
-        qlat, qbw = _queue_tables(sched, latf, bwf)
+        with jax.named_scope("fitness.decode"):
+            sched = decode(a, p, num_accels)
+        with jax.named_scope("fitness.tables"):
+            qlat, qbw = _queue_tables(sched, latf, bwf)
         return qlat, qbw, sched.count
 
     qlat, qbw, count = jax.vmap(tables_one)(accel, prio)

@@ -44,7 +44,10 @@ def scan_strategy(strategy: SearchStrategy, state, eval_fn, group_size: int,
     """Run ``generations`` ask->eval->tell steps as one ``lax.scan``.
 
     Returns ``(best_fit, best_accel, best_prio, history, state)`` with
-    ``history`` the per-generation best-so-far curve.
+    ``history`` the per-generation best-so-far curve.  The three phases
+    run under the named scopes ``search.ask``, ``search.eval`` and
+    ``search.tell``: HLO metadata only, which a device trace reads back
+    through ``repro.core.sweep.executable_scopes``.
 
     Multi-objective strategies (``strategy.multi_objective``) run the same
     scan with ``eval_fn`` returning a ``(P, M)`` matrix: ``tell`` consumes
@@ -68,9 +71,12 @@ def scan_strategy(strategy: SearchStrategy, state, eval_fn, group_size: int,
 
     def step(carry, _):
         state, bf, ba, bp = carry
-        state, accel, prio = strategy.ask(state)
-        fit, bf, ba, bp = eval_update(accel, prio, bf, ba, bp)
-        state = strategy.tell(state, fit)
+        with jax.named_scope("search.ask"):
+            state, accel, prio = strategy.ask(state)
+        with jax.named_scope("search.eval"):
+            fit, bf, ba, bp = eval_update(accel, prio, bf, ba, bp)
+        with jax.named_scope("search.tell"):
+            state = strategy.tell(state, fit)
         return (state, bf, ba, bp), bf
 
     G = group_size
@@ -78,11 +84,14 @@ def scan_strategy(strategy: SearchStrategy, state, eval_fn, group_size: int,
               jnp.zeros((G,), jnp.int32), jnp.zeros((G,), jnp.float32))
     carry, hist = jax.lax.scan(step, carry0, None, length=generations - 1)
     state, bf, ba, bp = carry
-    state, accel, prio = strategy.ask(state)
-    fit, bf, ba, bp = eval_update(accel, prio, bf, ba, bp)
+    with jax.named_scope("search.ask"):
+        state, accel, prio = strategy.ask(state)
+    with jax.named_scope("search.eval"):
+        fit, bf, ba, bp = eval_update(accel, prio, bf, ba, bp)
     hist = jnp.concatenate([hist, bf[None]])
     if evolve_last or mo:    # legacy loop evolves once more; mo archives
-        state = strategy.tell(state, fit)
+        with jax.named_scope("search.tell"):
+            state = strategy.tell(state, fit)
     return bf, ba, bp, hist, state
 
 

@@ -41,9 +41,10 @@ with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from functools import lru_cache, partial
-from typing import List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import numpy as np
@@ -224,6 +225,91 @@ def row_executable(strategy: SearchStrategy, generations: int,
     return fn, target
 
 
+# the search's named scopes (``scan_strategy``, ``bw_allocator``), which
+# a device trace's operations are attributed to; ``other`` is the rest
+SEARCH_SCOPES = ("search.ask", "search.eval", "search.tell",
+                 "fitness.decode", "fitness.tables", "fitness.events")
+_SCOPE_RE = re.compile("|".join(re.escape(s) for s in SEARCH_SCOPES))
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+# every row executable that has run in this process, recorded at its
+# first dispatch: (fn, padded rows) -> (abstract arguments, static shape)
+_RAN: Dict[tuple, tuple] = {}
+
+
+def _note_run(fn, args, strategy: SearchStrategy, generations: int,
+              num_devices: int) -> None:
+    """Record a row executable's abstract arguments ``(keys, params[,
+    warm])`` once, for :func:`executable_scopes`; a dictionary lookup on
+    later dispatches."""
+    key = (fn, args[0].shape[0])
+    if key in _RAN:
+        return
+    abstract = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+        args)
+    _RAN[key] = (abstract, dict(
+        P=strategy.ask_size, A=strategy.num_accels,
+        G=int(args[1].lat.shape[-2]),
+        generations=generations, rows=args[0].shape[0],
+        devices=num_devices))
+
+
+@dataclasses.dataclass(frozen=True)
+class ExecutableScopes:
+    """One compiled row executable's operations by named scope.
+
+    ``scopes`` maps each HLO instruction name (as a device trace names
+    the operation, without ``%``) to the innermost of
+    :data:`SEARCH_SCOPES` in its ``op_name`` metadata, or ``other``.
+    ``module`` is the HLO module's name, as the trace names its runs
+    (before the run's program id).  The static shape: population ``P``,
+    ``A`` sub-accelerators, group ``G``, ``generations``, padded
+    ``rows`` over ``devices``."""
+    module: str
+    scopes: Dict[str, str]
+    P: int
+    A: int
+    G: int
+    generations: int
+    rows: int
+    devices: int
+
+
+def instruction_scopes(hlo_text: str) -> Dict[str, str]:
+    """{instruction name -> innermost search scope or ``other``} of an
+    HLO module's text."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME_RE.search(line)
+        found = _SCOPE_RE.findall(op.group(1)) if op else ()
+        out[m.group(1)] = found[-1] if found else "other"
+    return out
+
+
+def executable_scopes() -> List[ExecutableScopes]:
+    """Every row executable that has run in this process, its operations
+    mapped to the search's named scopes from the compiled module's text.
+
+    For reading a device trace after the fact: each executable is looked
+    up again by its recorded abstract arguments (the in-memory or
+    persistent compile cache answers), never on the dispatch path."""
+    out = []
+    for (fn, _), (args, shape) in list(_RAN.items()):
+        if not hasattr(fn, "lower"):      # a caller's wrapper, not jitted
+            continue
+        text = fn.lower(*args).compile().as_text()
+        m = re.match(r"HloModule ([^\s,]+)", text)
+        out.append(ExecutableScopes(module=m.group(1) if m else "",
+                                    scopes=instruction_scopes(text),
+                                    **shape))
+    return out
+
+
 @lru_cache(maxsize=None)
 def _sweep_mesh(num_devices: int):
     """Meshes cached by size: a fresh Mesh per call would miss the jit
@@ -365,6 +451,7 @@ def run_rows(rows_params: FitnessParams, rows_keys, *,
     outs, walls = [], []
     with transfer_sanitizer(sweep.transfer_guard):
         buf = put_chunk(0)
+        _note_run(fn, buf, strategy, generations, ndev)
         for i in range(n_chunks):
             # double buffer: enqueue the NEXT chunk's host->device
             # transfer before dispatching this chunk's compute, so the

@@ -11,7 +11,9 @@ Design constraints, in order:
 * **Never inside jitted code.**  Spans wrap host-side work only
   (assembly, dispatch *enqueue*, block_until_ready, routing); a span
   around a device call measures the host's view of it.  Nothing here
-  may change what bytes a schedule contains.
+  may change what bytes a schedule contains.  (Inside jitted code the
+  search names its work with ``jax.named_scope`` instead: HLO metadata
+  only, see ``repro.core.sweep.executable_scopes``.)
 * **~zero overhead when disabled.**  ``span()`` on a disabled tracer
   returns one shared no-op context manager (no allocation), ``emit()``
   is a single attribute check.  The hot loops additionally gate their
@@ -20,6 +22,15 @@ Design constraints, in order:
   the main pipeline loop all emit concurrently; each span is built
   outside the lock and appended whole, so readers never observe a torn
   record.  Eviction is oldest-first (``dropped`` counts casualties).
+
+On an enabled tracer every live span (``span()``) also opens a
+``jax.profiler.TraceAnnotation`` of the same name for its lifetime,
+carrying its start on the tracer's clock as the argument ``run_s`` (and
+``scope`` / ``batch`` when set).  A profiler trace then shows the span
+on its thread's host line, and any one of them fixes the offset between
+the tracer's clock and the trace's, so retroactive ``emit()`` spans can
+be placed on the device timeline too.  With no profiler running the
+annotation records nothing.
 
 Two clock conventions coexist: the stream service passes its
 run-relative clock so span timestamps line up with ``StreamResult``
@@ -96,11 +107,33 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+_TraceAnnotation = None             # jax.profiler's, imported on first use
+
+
+def _annotation(name: str, start_s: float, scope: Optional[int],
+                args: Dict):
+    """An entered ``TraceAnnotation`` for a live span."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    extra = {}
+    if scope is not None:
+        extra["scope"] = scope
+    if "batch" in args:
+        extra["batch"] = args["batch"]
+    ann = _TraceAnnotation(name, run_s=start_s, **extra)
+    ann.__enter__()
+    return ann
+
+
 class _LiveSpan:
     """Open span handle: ``with tracer.span(...)`` or explicit
-    ``h = tracer.begin(...); ...; h.finish()``."""
+    ``h = tracer.span(...); ...; h.finish()``.  The profiler annotation
+    opens with the handle and closes at ``finish``."""
 
-    __slots__ = ("_tracer", "name", "scope", "args", "start_s", "_open")
+    __slots__ = ("_tracer", "name", "scope", "args", "start_s", "_open",
+                 "_annotation", "_profiled")
 
     def __init__(self, tracer: "Tracer", name: str, scope: Optional[int],
                  args: Dict) -> None:
@@ -110,15 +143,30 @@ class _LiveSpan:
         self.args = args
         self.start_s = tracer.now()
         self._open = True
+        self._annotation = _annotation(name, self.start_s, scope, args)
+        self._profiled = _TraceAnnotation.is_enabled()
 
     def set(self, **args) -> None:
         """Attach args discovered mid-span (e.g. memo lookup outcome)."""
         self.args.update(args)
 
+    def reannotate(self) -> None:
+        """Re-open the profiler annotation if a profiler started after it
+        opened (one opened with none running records nothing), so a
+        long-lived span shows in the trace from here on; its ``run_s`` is
+        then the re-opening instant."""
+        if self._open and not self._profiled \
+                and _TraceAnnotation.is_enabled():
+            self._annotation.__exit__(None, None, None)
+            self._annotation = _annotation(self.name, self._tracer.now(),
+                                           self.scope, self.args)
+            self._profiled = True
+
     def finish(self, **args) -> None:
         if not self._open:      # idempotent: CM exit after manual finish
             return
         self._open = False
+        self._annotation.__exit__(None, None, None)
         if args:
             self.args.update(args)
         self._tracer.emit(self.name, self.start_s, self._tracer.now(),
@@ -169,15 +217,12 @@ class Tracer:
             self._spans.append(span)
 
     def span(self, name: str, scope: Optional[int] = None, **args):
-        """Context manager measuring the enclosed block.  On a disabled
+        """Context manager measuring the enclosed block (or a handle to
+        ``finish()`` later), annotated for the profiler.  On a disabled
         tracer this returns the shared no-op handle."""
         if not self.enabled:
             return NULL_SPAN
         return _LiveSpan(self, name, scope, args)
-
-    def begin(self, name: str, scope: Optional[int] = None, **args):
-        """Explicit-start API: returns a handle; call ``.finish()``."""
-        return self.span(name, scope=scope, **args)
 
     def spans(self) -> List[Span]:
         """Snapshot of the buffer, oldest first."""

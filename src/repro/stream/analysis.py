@@ -107,9 +107,10 @@ class AnalysisPool:
     whatever the workers finish, which is exactly what the admission
     stage wants (it batches whoever is ready).  ``clock`` maps
     ``time.perf_counter()`` to the service's relative timeline.
-    ``tracer`` (a ``repro.obs`` span tracer) gets one ``analyze`` span
-    per scenario — emitted from the worker threads, which is exactly
-    the concurrency the tracer's lock exists for.
+    ``tracer`` (a ``repro.obs`` span tracer) gets one live ``analyze``
+    span per scenario — opened on the worker thread, so a profiler trace
+    shows it on that thread, and emitted from it, which is exactly the
+    concurrency the tracer's lock exists for.
     """
 
     def __init__(self, workers: int = 2, clock=None, tracer=None):
@@ -154,23 +155,21 @@ class AnalysisPool:
         from repro.costmodel.maestro import FlexibleMaestroModel
         from repro.workloads import build_task_groups
         t0 = self._clock()
-        group = build_task_groups(req.mix, group_size=req.group_size,
-                                  seed=req.seed)[0]
-        jobs = scale_jobs(group.jobs, req.batch_scale)
-        if fresh_analyzer:
-            analyzer = JobAnalyzer(
-                get_setting(req.setting),
-                model=FlexibleMaestroModel() if req.flexible else None)
-        else:
-            analyzer = self.analyzer_for(req.setting, req.flexible)
-        table = analyzer.analyze(jobs)
-        fit = FitnessFn(table, bw_sys=req.bw_gb * GB,
-                        objective=req.objective)
+        with self._tracer.span("analyze", scope=req.uid, setting=req.setting,
+                               mix=req.mix, fresh=fresh_analyzer):
+            group = build_task_groups(req.mix, group_size=req.group_size,
+                                      seed=req.seed)[0]
+            jobs = scale_jobs(group.jobs, req.batch_scale)
+            if fresh_analyzer:
+                analyzer = JobAnalyzer(
+                    get_setting(req.setting),
+                    model=FlexibleMaestroModel() if req.flexible else None)
+            else:
+                analyzer = self.analyzer_for(req.setting, req.flexible)
+            table = analyzer.analyze(jobs)
+            fit = FitnessFn(table, bw_sys=req.bw_gb * GB,
+                            objective=req.objective)
         t1 = self._clock()
-        if self._tracer.enabled:
-            self._tracer.emit("analyze", t0, t1, scope=req.uid,
-                              setting=req.setting, mix=req.mix,
-                              fresh=fresh_analyzer)
         return ReadyScenario(request=req, fit=fit, analysis_start_s=t0,
                              ready_s=t1)
 

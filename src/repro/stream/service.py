@@ -55,7 +55,8 @@ from repro.core.fitness import FitnessFn, ObjectiveSpec
 from repro.core.magma import MagmaConfig, SearchResult
 from repro.core.pareto import ParetoFront, pareto_front
 from repro.core.strategies import SearchStrategy, WarmStart, plan_generations
-from repro.core.sweep import _pad_rows, _resolve_strategy, row_executable
+from repro.core.sweep import (_note_run, _pad_rows, _resolve_strategy,
+                              row_executable)
 from repro.lint.runtime import transfer_sanitizer
 from repro.obs import (FlightRecorder, NULL_SPAN, NULL_TRACER, ObsConfig,
                        RunClock, Tracer, as_obs_config)
@@ -128,7 +129,8 @@ class StreamConfig:
                       over the wire — or None = disabled).  Enabled, the
                       service traces one span tree per scenario
                       (admit/analyze/queue_wait/dispatch/device/route +
-                      memo spans), runs a flight recorder, and feeds the
+                      memo spans) and the main loop's ``LOOP_PHASES``,
+                      runs a flight recorder, and feeds the
                       process metrics registry.  All host-side: spans
                       never wrap jitted code, schedules stay
                       bit-identical (perf_obs gates <3% overhead)
@@ -270,6 +272,7 @@ class _BatchRecord:
     padded_rows: int
     num_devices: int
     compat_key: Tuple
+    batch: int                      # the run's dispatch counter (obs arg)
 
 
 @dataclasses.dataclass
@@ -280,6 +283,53 @@ class _Inflight:
     padded_rows: int
     num_devices: int
     compat_key: Tuple
+    batch: int
+
+
+# the main loop's phases: with obs on, every instant of ``_run`` lies in
+# exactly one of these live spans
+INTAKE = "stream.loop.intake"              # submit arrivals, drain, admit
+DISPATCH = "stream.loop.dispatch"          # select, assemble, put, launch
+ROUTE_WAIT = "stream.loop.route_wait"      # block on the head batch
+ROUTE = "stream.loop.route"                # fetch, build results, record
+WAIT_ANALYSIS = "stream.loop.wait_analysis"  # wait for an analysis
+IDLE = "stream.loop.idle"                  # realtime sleep, nothing due
+LOOP_PHASES = (INTAKE, DISPATCH, ROUTE_WAIT, ROUTE, WAIT_ANALYSIS, IDLE)
+
+
+class _LoopPhases:
+    """The main loop's current phase, held open as one live span.
+
+    ``to(name, batch)`` closes the open phase and opens ``name`` unless
+    the loop is already in it, so consecutive iterations in one phase
+    (a long realtime idle) make one span; staying in it re-opens the
+    span's profiler annotation if a profiler has started meanwhile.  On
+    a disabled tracer it does nothing."""
+
+    __slots__ = ("_tracer", "_key", "_span")
+
+    def __init__(self, tracer: Tracer) -> None:
+        self._tracer = tracer
+        self._key: Optional[Tuple[str, Optional[int]]] = None
+        self._span = NULL_SPAN
+
+    def to(self, name: str, batch: Optional[int] = None) -> None:
+        if not self._tracer.enabled:
+            return
+        if self._key == (name, batch):
+            self._span.reannotate()
+            return
+        self._span.finish()
+        self._key = (name, batch)
+        self._span = (self._tracer.span(name) if batch is None
+                      else self._tracer.span(name, batch=batch))
+
+    def close(self) -> None:
+        self._span.finish()
+        self._key, self._span = None, NULL_SPAN
+
+
+_NO_PHASES = _LoopPhases(NULL_TRACER)
 
 
 class StreamingScheduler:
@@ -342,6 +392,7 @@ class StreamingScheduler:
         self.last_metrics: Optional[StreamMetrics] = None
         self.last_batches: List[_BatchRecord] = []   # @locked:_run_lock
         self._refined = 0            # @locked:_run_lock  silent refinements
+        self._dispatched = 0         # @locked:_run_lock  the next batch id
         # the last run's AdmissionQueues (counters: enqueued/dispatched/
         # stolen/depth/peak/early_flushes)  @locked:_run_lock
         self.last_admission: Optional[AdmissionQueues] = None
@@ -361,6 +412,7 @@ class StreamingScheduler:
         self.clock.reset()
         self.last_batches = []
         self._refined = 0
+        self._dispatched = 0
         if self.obs.enabled and self.obs.clear_per_run:
             self.tracer.clear()
 
@@ -415,6 +467,7 @@ class StreamingScheduler:
 
     def _dispatch(self, compat_key: CompatKey, members: List[ReadyScenario]
                   ) -> _Inflight:
+        """Assemble, transfer and launch one batch.  @holds:_run_lock"""
         base, G, A, use_kernel, objective, budget, is_warm = compat_key
         warm_seeded = bool(is_warm)     # compat-key flag, not key material
         t_dispatch = self.tracer.now() if self.tracer.enabled else 0.0
@@ -453,15 +506,16 @@ class StreamingScheduler:
         # batch assembly above is pure host numpy; only the transfers +
         # launch below run under the (optional) disallow guard
         with transfer_sanitizer(self.stream.transfer_guard):
-            keys_d = jax.device_put(keys, target)
-            params_d = jax.device_put(params, target)
+            args = (jax.device_put(keys, target),
+                    jax.device_put(params, target))
             if warm_seeded:
-                out = fn(keys_d, params_d, jax.device_put(warm, target))
-            else:
-                out = fn(keys_d, params_d)  # async: returns immediately
+                args += (jax.device_put(warm, target),)
+            _note_run(fn, args, strategy, generations, ndev)
+            out = fn(*args)             # async: returns immediately
         inf = _Inflight(out=out, members=members, dispatch_s=self._clock(),
                         padded_rows=padded, num_devices=ndev,
-                        compat_key=compat_key)
+                        compat_key=compat_key, batch=self._dispatched)
+        self._dispatched += 1
         if self.tracer.enabled:
             # host-side stamps only — the device work was launched above
             # and its span is emitted at route time, when its end is known
@@ -469,11 +523,11 @@ class StreamingScheduler:
                 uid = m.request.uid
                 self.tracer.emit("queue_wait",
                                  m.admitted_s or m.ready_s, t_dispatch,
-                                 scope=uid)
+                                 scope=uid, batch=inf.batch)
                 self.tracer.emit("dispatch", t_dispatch, inf.dispatch_s,
                                  scope=uid, rows=len(members),
                                  bucket=padded, devices=ndev,
-                                 warm=warm_seeded)
+                                 warm=warm_seeded, batch=inf.batch)
             if self.flight is not None:
                 self.flight.note("dispatch", rows=len(members),
                                  bucket=padded, devices=ndev,
@@ -494,11 +548,14 @@ class StreamingScheduler:
                              ready_s=now,
                              strategy=self._resolve_override(p.strategy))
 
-    def _route(self, inf: _Inflight, results: List[StreamResult]) -> None:
+    def _route(self, inf: _Inflight, results: List[StreamResult],
+               phases: _LoopPhases = _NO_PHASES) -> None:
         """Fetch a finished batch and route rows.  @holds:_run_lock"""
         with transfer_sanitizer(self.stream.transfer_guard):
+            phases.to(ROUTE_WAIT, inf.batch)
             jax.block_until_ready(inf.out)
             done = self._clock()
+            phases.to(ROUTE, inf.batch)
             outs = [jax.device_get(o) for o in inf.out]
         bf, ba, bp, hist = outs[:4]
         pops = outs[4:6] if len(outs) >= 6 else None
@@ -549,16 +606,16 @@ class StreamingScheduler:
         self.last_batches.append(_BatchRecord(
             dispatch_s=inf.dispatch_s, done_s=done, rows=len(inf.members),
             padded_rows=inf.padded_rows, num_devices=inf.num_devices,
-            compat_key=inf.compat_key))
+            compat_key=inf.compat_key, batch=inf.batch))
         if self.tracer.enabled:
             t_routed = self.tracer.now()
             for m in inf.members:
                 uid = m.request.uid
                 self.tracer.emit("device", inf.dispatch_s, done,
                                  scope=uid, rows=len(inf.members),
-                                 devices=inf.num_devices)
+                                 devices=inf.num_devices, batch=inf.batch)
                 self.tracer.emit("route", done, t_routed, scope=uid,
-                                 silent=m.silent)
+                                 silent=m.silent, batch=inf.batch)
             if self.flight is not None:
                 self.flight.note("route", rows=len(inf.members),
                                  device_s=done - inf.dispatch_s)
@@ -657,6 +714,8 @@ class StreamingScheduler:
         inflight: deque = deque()
         futs = set()
         results: List[StreamResult] = []
+        phases = _LoopPhases(self.tracer)
+        phases.to(INTAKE)
 
         def admit(ready: ReadyScenario):
             if self.tracer.enabled:
@@ -674,6 +733,7 @@ class StreamingScheduler:
             # 1. feed due arrivals into the analysis pool
             while to_submit and (not realtime
                                  or to_submit[0].arrival_s <= self._clock()):
+                phases.to(INTAKE)
                 req = to_submit.popleft()
                 if not realtime:
                     # as-fast-as-possible replay: arrival == submission
@@ -684,6 +744,8 @@ class StreamingScheduler:
             # 2. drain finished analyses into the admission queues
             if futs:
                 done, futs = wait(futs, timeout=0)
+                if done:
+                    phases.to(INTAKE)
                 for f in done:
                     admit(f.result())
                     progressed = bool(done) or progressed
@@ -708,25 +770,29 @@ class StreamingScheduler:
                 key = queues.select(self._clock(), bool(futs))
                 if key is None:
                     break          # hold the partials: more is coming
+                phases.to(DISPATCH, self._dispatched)
                 inflight.append(self._dispatch(key, queues.take(key)))
                 progressed = True
 
             # 4. route: block on the head batch when the pipeline is full
             if inflight and len(inflight) >= self.stream.max_inflight:
-                self._route(inflight.popleft(), results)
+                self._route(inflight.popleft(), results, phases)
                 progressed = True
 
             if not progressed:
                 if inflight:
                     # nothing else to do until the head batch finishes
                     # (held partials dispatch right after it routes)
-                    self._route(inflight.popleft(), results)
+                    self._route(inflight.popleft(), results, phases)
                 elif futs:         # analyses still running: wait for one
+                    phases.to(WAIT_ANALYSIS)
                     wait(futs, timeout=0.01, return_when=FIRST_COMPLETED)
                 elif realtime and to_submit:
+                    phases.to(IDLE)
                     time.sleep(min(0.01, max(
                         0.0, to_submit[0].arrival_s - self._clock())))
 
+        phases.close()
         wall = self._clock()
         results.sort(key=lambda r: r.request.uid)
         queues.check()               # enqueued == dispatched+stolen+depth

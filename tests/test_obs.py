@@ -52,12 +52,101 @@ def test_tracer_basics_and_clock():
 def test_disabled_tracer_records_nothing_and_shares_null_span():
     tr = Tracer(enabled=False)
     tr.emit("a", 0.0, 1.0)
-    assert tr.span("x") is NULL_SPAN is tr.begin("y")
+    assert tr.span("x") is NULL_SPAN
     with tr.span("x") as sp:
         sp.set(whatever=1)
         sp.finish()
     assert tr.spans() == [] and tr.dropped == 0
     assert NULL_TRACER.enabled is False
+
+
+def _profiled(log_dir, body):
+    """Run ``body()`` under the CPU profiler; the host events of the trace
+    as ``(name, start_ns, dur_ns, stats)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    return [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_live_span_reaches_the_profiler_on_its_run_clock(tmp_path):
+    """An enabled tracer's live span is a host event of the same name
+    carrying its run-clock start as ``run_s``; every such event gives the
+    same trace-minus-run offset, so one fixes the two clocks."""
+    import time
+    clock = RunClock()
+    tr = Tracer(clock=clock)
+
+    def body():
+        for i in range(4):
+            with tr.span(f"obs.test.{i}", scope=i, batch=10 + i):
+                time.sleep(0.002)
+            time.sleep(0.003)
+        h = tr.span("obs.test.explicit")
+        time.sleep(0.002)
+        h.finish()
+
+    events = {n: (s, d, st) for n, s, d, st in _profiled(tmp_path, body)
+              if n.startswith("obs.test.")}
+    assert sorted(events) == [f"obs.test.{i}" for i in range(4)] + \
+        ["obs.test.explicit"]
+    spans = {s.name: s for s in tr.spans()}
+    offsets = []
+    for name, (start_ns, dur_ns, stats) in events.items():
+        assert float(stats["run_s"]) == pytest.approx(spans[name].start_s,
+                                                      abs=1e-6)
+        assert dur_ns * 1e-9 == pytest.approx(spans[name].dur_s, abs=1e-3)
+        offsets.append(start_ns * 1e-9 - float(stats["run_s"]))
+    assert max(offsets) - min(offsets) < 1e-3
+    assert int(events["obs.test.2"][2]["scope"]) == 2
+    assert int(events["obs.test.2"][2]["batch"]) == 12
+
+
+def test_span_open_before_the_profiler_reannotates(tmp_path):
+    """A span opened before the profiler started records nothing there;
+    ``reannotate`` after the start shows it from then on."""
+    import time
+    tr = Tracer(clock=RunClock())
+    early = tr.span("obs.test.early")
+    late = tr.span("obs.test.late")
+
+    def body():
+        time.sleep(0.002)
+        t = tr.now()
+        early.reannotate()
+        early.reannotate()                  # once is enough
+        time.sleep(0.002)
+        early.finish()
+        late.finish()
+        assert tr.spans()[0].start_s < t
+
+    events = [(n, st) for n, _, _, st in _profiled(tmp_path, body)
+              if n.startswith("obs.test.")]
+    assert [n for n, _ in events] == ["obs.test.early"]
+    assert float(events[0][1]["run_s"]) > tr.spans()[0].start_s
+
+
+def test_disabled_tracer_leaves_no_host_event(tmp_path):
+    tr = Tracer(enabled=False)
+
+    def body():
+        with tr.span("obs.test.disabled", scope=1):
+            pass
+        tr.span("obs.test.disabled.explicit").finish()
+
+    names = {n for n, *_ in _profiled(tmp_path, body)}
+    assert not any(n.startswith("obs.test.disabled") for n in names)
 
 
 def test_ring_eviction_oldest_first():

@@ -278,6 +278,64 @@ def test_realtime_replay_orders_arrivals():
         assert r.done_s >= t.arrival_s
 
 
+def test_loop_phases_tile_a_realtime_run(tmp_path):
+    """With obs on, the main loop's ``stream.loop.*`` phases tile the run:
+    they never overlap, cover its wall time, reach the profiler's trace,
+    and a stretch of idle iterations is one span.  A request's
+    queue_wait/dispatch/device/route spans carry its batch's id."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    import dataclasses
+
+    from repro.stream.service import IDLE, LOOP_PHASES
+    # the first arrival 50 ms in: an idle stretch of several 10 ms sleeps
+    trace = [dataclasses.replace(r, arrival_s=r.arrival_s + 0.05)
+             for r in generate_trace(TraceConfig(num_scenarios=5,
+                                                 rate_hz=20.0, seed=6,
+                                                 **QUICK))]
+    svc = StreamingScheduler(
+        budget=BUDGET,
+        stream=StreamConfig(batch_rows=2, realtime=True,
+                            obs={"enabled": True}))
+    svc.warmup(trace)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        results = svc.run(trace)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(results) == len(trace)
+
+    spans = svc.tracer.spans()
+    phases = sorted((s for s in spans if s.name in LOOP_PHASES),
+                    key=lambda s: s.start_s)
+    for a, b in zip(phases, phases[1:]):
+        assert b.start_s >= a.end_s - 1e-9, (a, b)      # never overlap
+        assert not (a.name == b.name == IDLE)           # one idle stretch
+    assert phases[0].name != IDLE and phases[1].name == IDLE
+    assert phases[1].dur_s >= 0.04
+    wall = svc.last_metrics.wall_s
+    assert sum(p.dur_s for p in phases) >= 0.99 * wall
+
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    seen = {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert {p.name for p in phases} <= seen and "analyze" in seen
+
+    batches = {b.batch for b in svc.last_batches}
+    assert len(batches) == len(svc.last_batches)
+    for r in results:
+        mine = {s.name: s.args["batch"] for s in spans
+                if s.scope == r.request.uid
+                and s.name in ("queue_wait", "dispatch", "device", "route")}
+        assert len(mine) == 4 and len(set(mine.values())) == 1, mine
+        assert set(mine.values()) <= batches
+
+
 # ---------------------------------------------------------------------------
 # SLO-aware admission: deadlines, priority classes, anytime schedules
 # ---------------------------------------------------------------------------

@@ -142,6 +142,86 @@ def test_repeat_sweep_reuses_compiled_chunk_fn():
     assert n1.hits == n0.hits + 1
 
 
+def test_executable_scopes_name_ask_eval_and_tell():
+    """The compiled row executable's instructions map to the search's
+    named scopes; its static shape comes with the map."""
+    from repro.core.strategies import get_strategy
+    from repro.core.sweep import executable_scopes
+    fns = _grid(2)
+    run_sweep(fns, budget=BUDGET, seeds=[0, 1], strategy="de")
+    P = get_strategy("de").ask_size
+    mine = [e for e in executable_scopes()
+            if (e.P, e.A, e.G, e.rows) == (P, 3, 16, 4)]
+    assert mine and mine[0].generations == BUDGET // P
+    assert mine[0].module and mine[0].devices >= 1
+    named = set(mine[0].scopes.values())
+    assert {"search.ask", "search.eval", "search.tell",
+            "fitness.events"} <= named
+
+
+def _program_lines(hlo_text):
+    """A compiled module's instruction lines with their metadata dropped
+    and every name replaced by its order of first appearance."""
+    import re
+    names = {}
+    out = []
+    for line in hlo_text.splitlines():
+        if " = " in line:
+            line = re.sub(r",? metadata=\{[^}]*\}", "", line)
+            out.append(re.sub(r"%[\w.\-]+", lambda m: "%" + str(
+                names.setdefault(m.group(0), len(names))), line))
+    return out
+
+
+def test_named_scopes_leave_the_compiled_program_unchanged():
+    """The search's named scopes are metadata: without them the compiled
+    row executable has the same instructions, up to their names."""
+    import contextlib
+
+    import jax.numpy as jnp
+    from repro.core.fitness import FitnessParams, ObjectiveSpec
+    from repro.core.strategies import MagmaStrategy, plan_generations
+    strategy = MagmaStrategy(CFG).bind(3)
+    gens, evolve_last = plan_generations(BUDGET, strategy.ask_size)
+
+    def compiled():
+        jax.clear_caches()
+        fn = _chunk_fn.__wrapped__(None, strategy, gens, evolve_last, 16,
+                                   False, ObjectiveSpec(("throughput",)))
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct((4,) + shape, dtype)
+        params = FitnessParams(lat=sds((16, 3)), bw=sds((16, 3)),
+                               bw_sys=sds(()), flops=sds(()),
+                               energy=sds((16, 3)),
+                               objective_code=sds((), jnp.int32))
+        return fn.lower(sds((2,), jnp.uint32), params).compile().as_text()
+
+    scoped = compiled()
+    assert "search.eval" in scoped and "fitness.events" in scoped
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        plain = compiled()
+    jax.clear_caches()              # no trace without scopes outlives this
+    assert "search.eval" not in plain
+    assert _program_lines(scoped) == _program_lines(plain)
+
+
+def test_instruction_scopes_take_the_innermost_scope():
+    from repro.core.sweep import instruction_scopes
+    text = "\n".join([
+        "HloModule jit_f, entry_computation_layout={()->f32[]}",
+        '  %fusion.3 = f32[4]{0} fusion(%p), kind=kLoop, '
+        'metadata={op_name="jit(f)/vmap(search.eval)/jit(g)/'
+        'fitness.events/while/body/add" stack_frame_id=2}',
+        '  ROOT %while.1 = (s32[]) while(%t), body=%b, '
+        'metadata={op_name="jit(f)/while"}',
+        "  %copy.2 = f32[4]{0} copy(%fusion.3)"])
+    assert instruction_scopes(text) == {"fusion.3": "fitness.events",
+                                        "while.1": "other",
+                                        "copy.2": "other"}
+
+
 def test_mixed_objectives_traced_branch():
     """Scenarios with different objectives share one compiled sweep (the
     traced per-scenario objective select) and still match standalone."""
