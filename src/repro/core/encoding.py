@@ -5,10 +5,12 @@ An individual = two genomes of length G (group size):
   accel genome   int32 in [0, A)   — sub-accelerator selection per job
   prio genome    float32 in [0, 1) — job priority (0 = highest)
 
-Decoding produces, per sub-accelerator, the ordered queue of its jobs.  For
-the vectorized simulator the queue is materialized as dense (A, G) arrays of
-job indices (argsort of priority with non-members pushed to the end) plus a
-per-accelerator count.  Everything is jit/vmap-friendly.
+Decoding produces, per sub-accelerator, the ordered queue of its jobs: one
+stable sort on (accel, prio) groups the jobs by accelerator in priority
+order.  ``decode`` materializes the queues as dense (A, G) arrays of job
+indices plus a per-accelerator count, for the Pallas makespan kernel and the
+host helpers; the jnp simulator (``repro.core.bw_allocator``) runs on the
+sort's grouped order itself.  Everything is jit/vmap-friendly.
 """
 from __future__ import annotations
 
