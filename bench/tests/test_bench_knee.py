@@ -9,7 +9,7 @@ from benchlib import cells, traffic
 from benchlib.runner import Run
 
 KNEE = os.path.join(cells.BENCH, "knee.py")
-CELL = "table3.served.poisson"
+CELL = cells.of_driver("served")[0]
 
 
 @pytest.fixture(scope="module")
@@ -53,7 +53,7 @@ def test_backlog_beyond_the_pipeline_does_not_keep_up(knee, late,
 def test_the_served_session_serves_offered_requests():
     cell = cells.cell(CELL)
     session = cells.driver("served").Session(cell, 7, 51.0, False, None)
-    assert len(session.requests) == 102
+    assert len(session.requests) == round(cell.traffic["rate_hz"] * 51.0)
     other = traffic.requests(dict(cell.traffic, rate_hz=1.0), cell.config,
                              7, 51.0, realisation=3)
     session.offer(other)

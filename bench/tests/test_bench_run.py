@@ -14,7 +14,8 @@ import pytest
 from benchlib import cells
 
 RUN = os.path.join(cells.BENCH, "run.py")
-ARGS = ["--workload", "s4.offline.grid", "--seed", "3", "--seconds", "1",
+OFFLINE = cells.of_driver("offline")[0]
+ARGS = ["--workload", OFFLINE, "--seed", "3", "--seconds", "1",
         "--trace", "0"]
 
 
@@ -45,7 +46,7 @@ def test_exits_nonzero_with_only_the_benchmark_files(tmp_path):
 
 
 def small_offline_cell():
-    cell = cells.cell("s4.offline.grid")
+    cell = cells.cell(OFFLINE)
     cell.traffic = dict(cell.traffic, mixes=["Lang", "Recom"],
                         bw_gb=[16, 64], seeds_per_scenario=2)
     return cell
